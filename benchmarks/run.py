@@ -16,6 +16,7 @@ from benchmarks import (decode_attention, fig3_splitting, fig4_params,
                         step_launches, table1_models, table23_cascade,
                         table4_three_element, table5_hard_task,
                         table6_accuracy_effect, table7_llm_cascade)
+from repro.launch.compile_cache import use_compile_cache
 
 ARTIFACTS = {
     "table1": table1_models.main,
@@ -36,6 +37,7 @@ ARTIFACTS = {
 
 def main() -> None:
     names = sys.argv[1:] or list(ARTIFACTS)
+    use_compile_cache()
     failures = []
     for name in names:
         print(f"\n# ===== {name} =====", flush=True)

@@ -20,7 +20,6 @@ from repro.launch.dryrun import _terms, corrected_costs, lower_cfg
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
 from repro.launch.shapes import SHAPES, input_specs
 from repro.models import params as params_lib
-from repro.models import sharding as sharding_lib
 
 
 def measure(cfg, shape_name, mesh, *, correct=True, microbatches=1,
@@ -28,7 +27,7 @@ def measure(cfg, shape_name, mesh, *, correct=True, microbatches=1,
     if chunked_ce:
         pshapes = params_lib.param_shapes(cfg, dtype=jnp.bfloat16, mesh=mesh)
         inputs = input_specs(cfg, shape_name, mesh, dtype=jnp.bfloat16)
-        with sharding_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, opt = steps_lib.make_train_step(cfg, chunked_ce=chunked_ce)
             osh = steps_lib.opt_state_shapes(opt, cfg, mesh)
             lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
@@ -38,7 +37,7 @@ def measure(cfg, shape_name, mesh, *, correct=True, microbatches=1,
         # custom lowering with grad accumulation
         pshapes = params_lib.param_shapes(cfg, dtype=jnp.bfloat16, mesh=mesh)
         inputs = input_specs(cfg, shape_name, mesh, dtype=jnp.bfloat16)
-        with sharding_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step, opt = steps_lib.make_train_step(cfg,
                                                   microbatches=microbatches)
             osh = steps_lib.opt_state_shapes(opt, cfg, mesh)
@@ -49,7 +48,7 @@ def measure(cfg, shape_name, mesh, *, correct=True, microbatches=1,
         pshapes = params_lib.param_shapes(cfg, dtype=jnp.bfloat16, mesh=mesh)
         inputs = input_specs(cfg, shape_name, mesh, dtype=jnp.bfloat16,
                              seq_over_model=True)
-        with sharding_lib.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             serve_step = steps_lib.make_serve_step(cfg)
             lowered = jax.jit(serve_step, donate_argnums=(3,)).lower(
                 pshapes, inputs["token"], inputs["pos"], inputs["cache"])

@@ -323,12 +323,15 @@ def register(cfg: ModelConfig) -> ModelConfig:
 
 
 def get_config(name: str, variant: Optional[str] = None) -> ModelConfig:
+    """The registered config, or a variant of it: ``smoke`` (reduced),
+    ``long`` (sliding-window), or ``full`` (the published widths, the
+    same as no variant)."""
     cfg = _REGISTRY[name]
     if variant == "smoke":
         return smoke_variant(cfg)
     if variant == "long":
         return long_context_variant(cfg)
-    if variant:
+    if variant and variant != "full":
         raise ValueError(f"unknown variant {variant!r}")
     return cfg
 

@@ -113,6 +113,11 @@ def teacher_task(num_samples: int = 200000, num_classes: int = 10,
     return ds
 
 
+# largest vocabulary whose trigram table is stored densely (8·vocab²
+# bytes: 128 MiB here, 320 GB at phi4-mini's 200064 tokens)
+DENSE_TRIGRAM_MAX_VOCAB = 4096
+
+
 def bigram_lm(num_seqs: int = 2000, seq_len: int = 128, vocab: int = 256,
               branching: int = 4, trigram_frac: float = 0.3,
               seed: int = 0, table_seed=None) -> np.ndarray:
@@ -121,13 +126,24 @@ def bigram_lm(num_seqs: int = 2000, seq_len: int = 128, vocab: int = 256,
     Each token has `branching` plausible successors (uniform).  With
     probability `trigram_frac`, the successor is instead determined by the
     previous *two* tokens — structure only a higher-capacity model captures.
+    Above ``DENSE_TRIGRAM_MAX_VOCAB`` tokens that successor is a seeded
+    hash of the two tokens instead of an entry of a dense table.
     Returns int32 [num_seqs, seq_len].
     """
     # transition tables come from table_seed so held-out splits can sample
     # NEW sequences from the SAME process (table_seed fixed, seed varied)
     trng = np.random.default_rng(seed if table_seed is None else table_seed)
     bigram = trng.integers(0, vocab, size=(vocab, branching))
-    trigram = trng.integers(0, vocab, size=(vocab, vocab))
+    if vocab <= DENSE_TRIGRAM_MAX_VOCAB:
+        table = trng.integers(0, vocab, size=(vocab, vocab))
+
+        def trigram(prev, tok):
+            return table[prev, tok]
+    else:
+        a, b, c = (int(x) for x in trng.integers(1, vocab, size=3))
+
+        def trigram(prev, tok):
+            return (prev * a + tok * b + c) % vocab
     rng = np.random.default_rng(seed)
     out = np.empty((num_seqs, seq_len), np.int32)
     tok = rng.integers(0, vocab, size=num_seqs)
@@ -136,7 +152,7 @@ def bigram_lm(num_seqs: int = 2000, seq_len: int = 128, vocab: int = 256,
         out[:, t] = tok
         use_tri = rng.random(num_seqs) < trigram_frac
         nxt_bi = bigram[tok, rng.integers(0, branching, size=num_seqs)]
-        nxt_tri = trigram[prev, tok]
+        nxt_tri = trigram(prev, tok)
         nxt = np.where(use_tri, nxt_tri, nxt_bi)
         prev, tok = tok, nxt.astype(np.int64)
     return out

@@ -1,14 +1,21 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in this
-CPU container (kernel bodies execute in Python) and compile to Mosaic on
-real TPU.  Model code opts in via config/env; the jnp paths in
-repro.models.blocks remain the default substrate.
+``interpret`` defaults to True on the CPU backend, so the same call sites
+run the kernel bodies in Python there (the tests), and to False on TPU,
+where they compile to Mosaic.  Any other backend is an error rather than
+a silent fallback to interpretation.
+
+Under a multi-device mesh (a serving tier's mesh, activated with
+``jax.set_mesh``) the serving kernels run inside ``shard_map``: the TPU
+compiler cannot partition a Mosaic kernel itself.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels.confidence_gate import confidence_gate as _gate
 from repro.kernels.flash_attention import flash_attention as _flash
@@ -23,12 +30,29 @@ from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on tpu or interpreted on cpu; "
+            f"backend {backend!r} is neither")
+    return backend == "cpu"
+
+
+def _multi_device_mesh():
+    """The mesh the caller is traced under, or None on one device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty or mesh.size == 1 else mesh
 
 
 def confidence_gate(logits, *, interpret=None):
-    return _gate(logits, interpret=_default_interpret()
-                 if interpret is None else interpret)
+    gate = functools.partial(_gate, interpret=_default_interpret()
+                             if interpret is None else interpret)
+    mesh = _multi_device_mesh()
+    if mesh is None:
+        return gate(logits)
+    # every device of the mesh gates the whole (replicated) batch
+    return jax.shard_map(gate, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(logits)
 
 
 def spec_accept(argmax_w, conf_w, q_len, flat_tokens, k):
@@ -111,11 +135,53 @@ def mixed_attention(q, k_pages, v_pages, page_table, q_start, q_len, *,
 def ragged_attention(q, k_pages, v_pages, page_table, q_start, q_len, *,
                      k_scale=None, v_scale=None, window=None,
                      tile_q=16, interpret=None):
-    return _ragged(q, k_pages, v_pages, page_table, q_start, q_len,
-                   k_scale=k_scale, v_scale=v_scale, window=window,
-                   tile_q=tile_q,
-                   interpret=_default_interpret()
-                   if interpret is None else interpret)
+    kernel = functools.partial(
+        _ragged, window=window, tile_q=tile_q,
+        interpret=_default_interpret() if interpret is None else interpret)
+    mesh = _multi_device_mesh()
+    if mesh is None:
+        return kernel(q, k_pages, v_pages, page_table, q_start, q_len,
+                      k_scale=k_scale, v_scale=v_scale)
+    return _ragged_on_data_shards(kernel, mesh, q, k_pages, v_pages,
+                                  page_table, q_start, q_len,
+                                  k_scale, v_scale)
+
+
+def _ragged_on_data_shards(kernel, mesh, q, k_pages, v_pages, page_table,
+                           q_start, q_len, k_scale, v_scale):
+    """The ragged kernel on a tier mesh, one call per data shard.
+
+    A data shard owns a contiguous range of engine rows and the
+    contiguous range of KV blocks those rows use
+    (:class:`repro.serving.slots.TierSlotPool`), and the flat batch packs
+    rows in order, so shard ``s``'s tokens are one contiguous flat span
+    starting after every earlier shard's tokens.  Each shard rolls its
+    span to the front, attends its own rows over its own blocks (page
+    ids made shard-local), and rolls the result back; a ``psum`` over the
+    data axes assembles the flat output, since every slot has one owner
+    and the other shards contribute zeros.  Any 'model' axis runs the
+    kernel replicated.
+    """
+    data = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
+    quant = k_scale is not None
+
+    def body(q, kp, vp, pt, qs, ql, ql_all, *scales):
+        shard = jax.lax.axis_index(data)
+        rows = jnp.arange(ql_all.shape[0])
+        offset = jnp.sum(jnp.where(rows < shard * pt.shape[0], ql_all, 0))
+        ks, vs = scales if quant else (None, None)
+        out = kernel(jnp.roll(q, -offset, axis=0), kp, vp,
+                     jnp.maximum(pt - shard * kp.shape[0], 0), qs, ql,
+                     k_scale=ks, v_scale=vs)
+        return jax.lax.psum(jnp.roll(out, offset, axis=0), data)
+
+    pool, rows = P(data), P(data)
+    in_specs = (P(), pool, pool, rows, rows, rows, P()) \
+        + ((pool, pool) if quant else ())
+    scales = (k_scale, v_scale) if quant else ()
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                         check_vma=False)(
+        q, k_pages, v_pages, page_table, q_start, q_len, q_len, *scales)
 
 
 def rwkv6_scan(r, k, v, w, u, *, interpret=None):

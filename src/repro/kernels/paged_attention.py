@@ -7,14 +7,18 @@ One new token per row attends to its own pages only — decode attention
 work is O(Σ per-row live tokens) instead of O(rows · max_seq), and arena
 memory is decoupled from ``prompt_len + gen_len``.
 
-Grid = (rows, kv_heads, pages) with the page sweep innermost: the online
-softmax accumulators (acc, m, l — the streaming pattern from
-``confidence_gate.py``) live in VMEM scratch and persist across the page
-sweep of each (row, head).  The page table and per-row positions are
-scalar-prefetched (:class:`pltpu.PrefetchScalarGridSpec`) so the KV block
-DMA of step ``(b, k, j)`` is gathered through ``page_table[b, j]`` in the
-BlockSpec index map — the kernel never sees a dense ``[rows, max_seq]``
-arena.
+Grid = (rows, pages) with the page sweep innermost: the online softmax
+accumulators (acc, m, l — the streaming pattern from
+``confidence_gate.py``) live in VMEM scratch, one slab per KV head, and
+persist across the page sweep of each row.  The page table and per-row
+positions are scalar-prefetched (:class:`pltpu.PrefetchScalarGridSpec`)
+so the KV block DMA of step ``(b, j)`` is gathered through
+``page_table[b, j]`` in the BlockSpec index map — the kernel never sees a
+dense ``[rows, max_seq]`` arena.  Each step gathers every KV head of the
+page, ``(1, bs, KV, hd)``, and loops over the heads statically, as
+:mod:`repro.kernels.ragged_attention` does: a one-head block ``(1, bs,
+1, hd)`` would put a dim of 1 second-minor, which the TPU compiler
+refuses unless it is the whole KV axis.
 
 Pages past a row's depth are skipped with ``pl.when`` (no FLOPs); their
 table entries point at block 0 (the reserved null block) so the gather
@@ -42,9 +46,9 @@ _NEG = -1e30
 
 def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
                   o_ref, acc_ref, m_ref, l_ref, *, ks_ref, vs_ref,
-                  bs: int, scale: float, window, np_: int):
+                  bs: int, KV: int, scale: float, window, np_: int):
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -59,33 +63,34 @@ def _paged_kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref,
 
     @pl.when(live)
     def _accumulate():
-        q = q_ref[0, 0].astype(jnp.float32)            # [G, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)         # [bs, hd]
-        v = v_ref[0, :, 0].astype(jnp.float32)         # [bs, hd]
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-        if ks_ref is not None:
-            s = s * ks_ref[0, :, 0][None, :]           # fused k dequant
-        t = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = t <= p
-        if window is not None:
-            mask &= t > p - window
-        s = jnp.where(mask, s, _NEG)
+        for h in range(KV):                # static unroll: plain 2D dots
+            q = q_ref[0, h].astype(jnp.float32)            # [G, hd]
+            k = k_ref[0, :, h].astype(jnp.float32)         # [bs, hd]
+            v = v_ref[0, :, h].astype(jnp.float32)         # [bs, hd]
+            s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+            if ks_ref is not None:
+                s = s * ks_ref[0, :, h][None, :]           # fused k dequant
+            t = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            mask = t <= p
+            if window is not None:
+                mask &= t > p - window
+            s = jnp.where(mask, s, _NEG)
 
-        m_old = m_ref[...]
-        m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
-        corr = jnp.exp(m_old - m_new)
-        e = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * corr + jnp.sum(e, axis=1)
-        if vs_ref is not None:
-            e = e * vs_ref[0, :, 0][None, :]           # fused v dequant
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
-            e, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            m_old = m_ref[h]
+            m_new = jnp.maximum(m_old, jnp.max(s, axis=1))
+            corr = jnp.exp(m_old - m_new)
+            e = jnp.exp(s - m_new[:, None])
+            l_ref[h] = l_ref[h] * corr + jnp.sum(e, axis=1)
+            if vs_ref is not None:
+                e = e * vs_ref[0, :, h][None, :]           # fused v dequant
+            acc_ref[h] = acc_ref[h] * corr[:, None] + jnp.dot(
+                e, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == np_ - 1)
     def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)[..., None]
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -114,28 +119,28 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     scale = 1.0 / math.sqrt(hd)
     quant = k_scale is not None
 
-    def idx_q(b, k, j, pt, pp):
-        return (b, k, 0, 0)
+    def idx_q(b, j, pt, pp):
+        return (b, 0, 0, 0)
 
-    def idx_kv(b, k, j, pt, pp):
-        return (pt[b, j], 0, k, 0)
+    def idx_kv(b, j, pt, pp):
+        return (pt[b, j], 0, 0, 0)
 
-    def idx_sc(b, k, j, pt, pp):
-        return (pt[b, j], 0, k)
+    def idx_sc(b, j, pt, pp):
+        return (pt[b, j], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, G, hd), idx_q),
-        pl.BlockSpec((1, bs, 1, hd), idx_kv),
-        pl.BlockSpec((1, bs, 1, hd), idx_kv),
+        pl.BlockSpec((1, KV, G, hd), idx_q),
+        pl.BlockSpec((1, bs, KV, hd), idx_kv),
+        pl.BlockSpec((1, bs, KV, hd), idx_kv),
     ]
     operands = [q, k_pages, v_pages]
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), idx_sc),
-                     pl.BlockSpec((1, bs, 1), idx_sc)]
+        in_specs += [pl.BlockSpec((1, bs, KV), idx_sc),
+                     pl.BlockSpec((1, bs, KV), idx_sc)]
         operands += [k_scale, v_scale]
 
     kernel = functools.partial(
-        _paged_kernel, bs=bs, scale=scale, window=window, np_=P)
+        _paged_kernel, bs=bs, KV=KV, scale=scale, window=window, np_=P)
 
     def body(pt_ref, pos_ref, *rest):
         if quant:
@@ -149,13 +154,13 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KV, P),
+        grid=(B, P),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd), idx_q),
+        out_specs=pl.BlockSpec((1, KV, G, hd), idx_q),
         scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),   # acc
-            pltpu.VMEM((G,), jnp.float32),      # running max m
-            pltpu.VMEM((G,), jnp.float32),      # running Σexp l
+            pltpu.VMEM((KV, G, hd), jnp.float32),   # acc
+            pltpu.VMEM((KV, G), jnp.float32),       # running max m
+            pltpu.VMEM((KV, G), jnp.float32),       # running Σexp l
         ],
     )
     return pl.pallas_call(

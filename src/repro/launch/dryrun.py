@@ -31,7 +31,6 @@ from repro.launch import steps as steps_lib
 from repro.launch.mesh import make_production_mesh, num_chips
 from repro.launch.shapes import SHAPES, input_specs
 from repro.models import params as params_lib
-from repro.models import sharding as sharding_lib
 
 
 def config_for(arch: str, shape_name: str):
@@ -52,7 +51,7 @@ def lower_cfg(cfg, shape_name: str, mesh, *, dtype=jnp.bfloat16,
     pshapes = params_lib.param_shapes(cfg, dtype=dtype, mesh=mesh)
     inputs = input_specs(cfg, shape_name, mesh, dtype=dtype)
 
-    with sharding_lib.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             train_step, opt = steps_lib.make_train_step(cfg)
             oshapes = steps_lib.opt_state_shapes(opt, cfg, mesh, dtype=jnp.float32)
@@ -72,8 +71,6 @@ def lower_cfg(cfg, shape_name: str, mesh, *, dtype=jnp.bfloat16,
 
 def _terms(compiled):
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax 0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     from repro.launch.hlo import collective_stats
     st = collective_stats(hlo)

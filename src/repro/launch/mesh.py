@@ -21,11 +21,8 @@ ICI_BW = 50e9                   # bytes/s per link
 
 
 def _mk(shape, axes):
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:           # jax >= 0.5
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)   # 0.4.x: Auto is the only mode
+    return jax.make_mesh(shape, axes, axis_types=(
+        jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -48,8 +45,8 @@ def make_tier_mesh(data: int = 1, model: int = 1, devices=None):
     the host's devices (the heavy tier typically gets more chips), so
     unlike :func:`make_test_mesh` this accepts an explicit device list.
     With ``devices=None`` and ``data*model`` covering every local device
-    it defers to the :func:`_mk` compat helper (AxisType on jax >= 0.5);
-    otherwise it builds the Mesh over the given slice directly.
+    it defers to :func:`_mk`; otherwise it builds the Mesh over the given
+    slice directly.
     """
     import numpy as np
     shape, axes = (data, model), ("data", "model")

@@ -31,6 +31,7 @@ from repro.configs import get_config
 from repro.core import confidence as conf_lib
 from repro.data import bigram_lm
 from repro.kernels import ops as kernel_ops
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_cache, init_params, transformer
 from repro.serving import CascadeEngine, TierSpec
 from repro.serving.engine import VirtualClock
@@ -172,6 +173,7 @@ def main():
     ap.add_argument("--slots", type=int, default=None,
                     help="per-tier KV slot pool size (default: batch)")
     args = ap.parse_args()
+    use_compile_cache()
     serve_cascade(args.fast, args.expensive, variant=args.variant,
                   batch=args.batch, prompt_len=args.prompt_len,
                   gen_len=args.gen_len, delta=args.delta,

@@ -90,9 +90,11 @@ import numpy as np
 from repro.configs import get_config
 from repro.data import bigram_lm
 from repro.models import init_params
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_tier_meshes
 from repro.serving import CascadeEngine, FaultPlan, TierSpec, Tracer
 from repro.serving.engine import VirtualClock, WallClock
+from repro.serving.request import RequestState
 from repro.serving.observability import profile_window
 
 
@@ -116,16 +118,26 @@ def tier_meshes(args, num_tiers: int):
     return make_tier_meshes(shapes)
 
 
+def tier_params(cfg, seed: int, variant: str):
+    """Random tier params from ``seed``.  The smoke tiers stay float32;
+    published widths are built in bfloat16 (two float32 tiers of the
+    default cascade would not fit a 16 GB chip) by one jitted program, so
+    no float32 copy of a weight is ever materialized on the device."""
+    key = jax.random.PRNGKey(seed)
+    if variant == "smoke":
+        return init_params(cfg, key, jnp.float32)
+    return jax.jit(init_params, static_argnums=(0, 2))(cfg, key,
+                                                      jnp.bfloat16)
+
+
 def build_engine(args, clock=None, tracer=None):
     fast_cfg = get_config(args.fast, args.variant)
     exp_cfg = get_config(args.expensive, args.variant)
-    fast_params = init_params(fast_cfg, jax.random.PRNGKey(args.seed),
-                              jnp.float32)
+    fast_params = tier_params(fast_cfg, args.seed, args.variant)
     exp_seed = getattr(args, "expensive_seed", None)
-    exp_params = init_params(
-        exp_cfg,
-        jax.random.PRNGKey(args.seed + 1 if exp_seed is None else exp_seed),
-        jnp.float32)
+    exp_params = tier_params(
+        exp_cfg, args.seed + 1 if exp_seed is None else exp_seed,
+        args.variant)
     gate_kw = ({"deltas": [args.delta]} if args.delta is not None
                else {"escalation_budget": args.escalation_budget})
     meshes = tier_meshes(args, 2)
@@ -341,6 +353,12 @@ def run(args, clock=None) -> dict:
     # sharded serving: per-tier mesh layout (None entries: single-device)
     summary["tier_meshes"] = engine.mesh_topology()
     summary["device_count"] = jax.device_count()
+    summary["params_bytes"] = [
+        sum(x.nbytes for x in jax.tree.leaves(t.params))
+        for t in engine.tiers]
+    summary["tokens_served"] = sum(
+        len(r.tokens) for r in engine.requests
+        if r.state is RequestState.DONE)
     return summary
 
 
@@ -447,7 +465,11 @@ def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--fast", default="gemma3-1b")
     ap.add_argument("--expensive", default="phi4-mini-3.8b")
-    ap.add_argument("--variant", default="smoke")
+    ap.add_argument("--variant", default="smoke",
+                    choices=("smoke", "full", "long"),
+                    help="tier widths: smoke (reduced, float32), full "
+                         "(published widths, bfloat16) or long "
+                         "(published widths with sliding windows)")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--rate", type=float, default=8.0,
                     help="Poisson arrival rate, requests/s")
@@ -604,6 +626,7 @@ def make_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = make_parser().parse_args()
+    use_compile_cache()
     clock = VirtualClock() if args.virtual_clock else None
     summary = run(args, clock)
     report(summary)

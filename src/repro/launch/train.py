@@ -22,6 +22,7 @@ from repro.checkpoint import save as save_ckpt
 from repro.configs import get_config
 from repro.data import Batches, bigram_lm
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import init_params
 
 
@@ -91,6 +92,7 @@ def main():
     ap.add_argument("--cost-c", type=float, default=0.5)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     run(args.arch, variant=args.variant, steps=args.steps, batch=args.batch,
         seq=args.seq, lr=args.lr, expensive=args.expensive, ltc_w=args.ltc_w,
         cost_c=args.cost_c, ckpt=args.ckpt)

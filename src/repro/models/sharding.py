@@ -34,33 +34,13 @@ def data_axis_size(mesh) -> int:
     return _axis_total(mesh, [a for a in ("pod", "data") if a in sizes])
 
 
-def active_mesh():
-    """The mesh activated by :func:`set_mesh`, across jax versions:
-    ``jax.sharding.get_abstract_mesh`` (jax >= 0.5) or the ``with mesh:``
-    thread-resource context (0.4.x)."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    from jax._src import mesh as mesh_lib
-    pm = mesh_lib.thread_resources.env.physical_mesh
-    return None if pm.empty else pm
-
-
-def set_mesh(mesh):
-    """Context manager activating ``mesh`` (``jax.set_mesh`` on jax >= 0.5;
-    on 0.4.x a ``Mesh`` is itself the context manager)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def shard_seq_if_heads_unshardable(x, num_heads: int):
     """x [B, T, KV, hd]: shard T over 'model' ONLY when the head dim
     cannot absorb the model axis (kv % model != 0).  With shardable heads
     the default head-parallel layout is already collective-free; forcing a
     T-shard there would just add resharding."""
-    mesh = active_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     m = dict(mesh.shape).get("model", 1)
     if m <= 1 or num_heads % m == 0:
@@ -69,8 +49,8 @@ def shard_seq_if_heads_unshardable(x, num_heads: int):
 
 
 def shard_hint(x, *spec):
-    mesh = active_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     sizes = dict(mesh.shape)
     resolved = []

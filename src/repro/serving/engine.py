@@ -62,11 +62,12 @@ prefill+decode from scratch through the idempotent chunk machinery
 (greedy decode is deterministic, so the replayed stream is
 bit-identical).  ``submit(deadline=)`` plus a per-tick shedding pass
 reject queued requests that cannot meet their deadline (``SHED``).
-Every launch and ``device_get`` runs under bounded retry-with-backoff;
-when retries exhaust the engine fails a single victim request
-(``FAILED``), never the run.  A :class:`repro.serving.faults.FaultPlan`
-injects all of these conditions deterministically behind
-zero-cost-when-None hooks.
+Every launch and ``device_get`` runs under bounded retry-with-backoff
+on transient errors; when retries exhaust the engine fails a single
+victim request (``FAILED``), never the run.  Any other device error (an
+OOM, a kernel fault) ends the run.  A
+:class:`repro.serving.faults.FaultPlan` injects all of these conditions
+deterministically behind zero-cost-when-None hooks.
 
 The clock is injectable: ``WallClock`` for real Poisson traffic,
 ``VirtualClock`` for deterministic tests (one tick per step).
@@ -284,15 +285,18 @@ class _TierRuntime:
             raise ValueError(
                 f"tier {spec.name}: {capacity} slots must divide into the "
                 f"mesh's {self.data_shards} data shards")
+        # the KV arena is stored in the tier's param dtype: float32 for
+        # the smoke tiers, bfloat16 at published widths
+        kv_dtype = spec.params["embed"].dtype
         if use_paged_kv:
-            self.pool = TierSlotPool(spec.cfg, capacity, max_seq,
+            self.pool = TierSlotPool(spec.cfg, capacity, max_seq, kv_dtype,
                                      block_size=block_size,
                                      num_blocks=kv_blocks, mesh=spec.mesh,
                                      prefix_chunk=(self.chunk if self.prefix
                                                    else None))
         else:
             self.pool = DenseTierSlotPool(spec.cfg, capacity, max_seq,
-                                          mesh=spec.mesh)
+                                          kv_dtype, mesh=spec.mesh)
         self.params = self._place_params(spec)
         self.slot_req: List[Optional[Request]] = [None] * capacity
         self.tok = np.zeros(capacity, np.int32)
@@ -420,9 +424,8 @@ class _TierRuntime:
 
         self.prefill_fn = jax.jit(prefill_fn)
         # Donate the cache so XLA updates the slot arena in place instead
-        # of copying it every token (2x peak cache memory otherwise).  CPU
-        # ignores donation and warns, so only donate on accelerators.
-        donate = (2,) if jax.default_backend() != "cpu" else ()
+        # of copying it every token (2x peak cache memory otherwise).
+        donate = (2,)
         self.step_fn = jax.jit(step_fn, donate_argnums=donate)
         self.chunk_fn = jax.jit(chunk_fn, donate_argnums=donate)
         self.mixed_fn = jax.jit(mixed_fn, donate_argnums=donate)
@@ -507,7 +510,7 @@ class _TierRuntime:
         against it); a no-op without a mesh."""
         if self.mesh is None:
             return contextlib.nullcontext()
-        return sharding_lib.set_mesh(self.mesh)
+        return jax.set_mesh(self.mesh)
 
     def run_prefill(self, prompts):
         with self._ctx():
@@ -618,16 +621,20 @@ class _RetryExhausted(RuntimeError):
         self.cause = cause
 
 
-def _transient_error_types() -> tuple:
-    """Exception classes the retry wrapper treats as transient: injected
-    :class:`repro.serving.faults.TransientError` always, plus the running
-    jax's runtime-error class (transfer hiccups, collective timeouts)
-    when it exposes one."""
-    types = [faults_lib.TransientError]
-    jax_err = getattr(getattr(jax, "errors", None), "JaxRuntimeError", None)
-    if jax_err is not None:
-        types.append(jax_err)
-    return tuple(types)
+# runtime statuses a relaunch can cure (a dropped transfer, a collective
+# timeout); any other status — RESOURCE_EXHAUSTED (device OOM), INTERNAL
+# (a kernel fault), INVALID_ARGUMENT — fails the same way again
+_TRANSIENT_STATUSES = ("UNAVAILABLE", "DEADLINE_EXCEEDED", "ABORTED")
+
+
+def _is_transient(e: BaseException) -> bool:
+    """Whether the retry wrapper may relaunch after ``e``: an injected
+    :class:`repro.serving.faults.TransientError`, or a jax runtime error
+    whose status is in :data:`_TRANSIENT_STATUSES`."""
+    if isinstance(e, faults_lib.TransientError):
+        return True
+    return (isinstance(e, jax.errors.JaxRuntimeError)
+            and str(e).startswith(_TRANSIENT_STATUSES))
 
 
 class CascadeEngine:
@@ -923,7 +930,6 @@ class CascadeEngine:
         self.launch_retries = int(launch_retries)
         self.retry_backoff = float(retry_backoff)
         self.faults = faults
-        self._transient = _transient_error_types()
         self._has_deadlines = False         # any submit carried a deadline
         self._min_tick_dt: Optional[float] = None   # shedding floor unit
         self._last_tick_t: Optional[float] = None
@@ -986,14 +992,14 @@ class CascadeEngine:
 
     def _launch(self, tier: int, kind: str, thunk):
         """Run one launch/transfer under bounded retry-with-backoff.
-        Transient failures (an injected
-        :class:`repro.serving.faults.TransientError`, or jax's runtime
-        error class) retry up to ``launch_retries`` times with doubling
-        ``retry_backoff``; relaunching is safe because the tick's plan is
-        pure host data built *before* any host state advances — replaying
-        it rewrites the same KV pages idempotently.  Exhaustion raises
+        Transient failures (see :func:`_is_transient`) retry up to
+        ``launch_retries`` times with doubling ``retry_backoff``;
+        relaunching is safe because the tick's plan is pure host data
+        built *before* any host state advances — replaying it rewrites
+        the same KV pages idempotently.  Exhaustion raises
         :class:`_RetryExhausted` for the call site to sacrifice a single
-        victim request (see :meth:`_fail_one`)."""
+        victim request (see :meth:`_fail_one`).  Any other error — a
+        device OOM, a kernel fault — propagates and ends the run."""
         delay = self.retry_backoff
         attempt = 0
         while True:
@@ -1001,7 +1007,10 @@ class CascadeEngine:
                 if self.faults is not None:
                     self.faults.pre_launch(self.tick_id, tier, kind, attempt)
                 return thunk()
-            except self._transient as e:
+            except (faults_lib.TransientError,
+                    jax.errors.JaxRuntimeError) as e:
+                if not _is_transient(e):
+                    raise
                 if self.tracer is not None:
                     self.tracer.instant("launch_retry", tier,
                                         tick=self.tick_id, kind=kind,
@@ -2066,9 +2075,15 @@ class CascadeEngine:
 
     def mesh_topology(self) -> List[dict]:
         """Per-tier mesh layout (None entries for unmeshed tiers): axis
-        sizes, device count/ids, data shard count, and whether params are
-        tensor-sharded — recorded into serving summaries and the BENCH
-        json."""
+        sizes, device count/ids, data shard count, whether params are
+        tensor-sharded, and the devices that actually hold the tier's
+        params and KV arena — recorded into serving summaries and the
+        BENCH json."""
+
+        def held_on(tree):
+            return sorted({d.id for x in jax.tree.leaves(tree)
+                           for d in x.devices()})
+
         out = []
         for rt in self.runtimes:
             if rt.mesh is None:
@@ -2083,6 +2098,8 @@ class CascadeEngine:
                 "device_ids": [int(d.id) for d in rt.mesh.devices.flat],
                 "data_shards": rt.data_shards,
                 "shard_params": bool(rt.spec.shard_params),
+                "param_device_ids": held_on(rt.params),
+                "kv_device_ids": held_on(rt.pool.cache),
             })
         return out
 
@@ -2118,7 +2135,7 @@ class CascadeEngine:
     def warmup(self) -> None:
         """Trigger tier compiles before the clock starts: one prefill +
         one decode per tier on dummy data.  The decode's returned cache is
-        rebound (step_fn donates its cache input on accelerators); the
+        rebound (step_fn donates its cache input); the
         dummy write lands in the reserved null block (paged: empty page
         tables point at block 0) or at position 0 of free rows (dense),
         neither of which the next occupant ever attends.  Ends by

@@ -338,6 +338,41 @@ def test_retry_exhaustion_fails_one_not_the_run(tiny_parts, ref_streams):
                              for rid, t in survivors.items())
 
 
+def _fail_nth_launch(eng, n: int, message: str):
+    """Make the fast tier's n-th ragged launch raise a jax runtime error
+    carrying ``message`` (its status comes first, as the runtime's do)."""
+    rt = eng.runtimes[0]
+    launch, calls = rt.ragged_fn, [0]
+
+    def failing(*args):
+        calls[0] += 1
+        if calls[0] == n:
+            raise jax.errors.JaxRuntimeError(message)
+        return launch(*args)
+
+    rt.ragged_fn = failing
+
+
+@pytest.mark.parametrize("status", ["RESOURCE_EXHAUSTED", "INTERNAL"])
+def test_device_error_ends_the_run(tiny_parts, status):
+    # an OOM or a kernel fault fails the same way on every relaunch: it
+    # must end the run, not be retried into one sacrificed request
+    eng = _build(tiny_parts)
+    _fail_nth_launch(eng, 3, f"{status}: injected device fault")
+    with pytest.raises(jax.errors.JaxRuntimeError, match=status):
+        _drain(eng, _prompts(tiny_parts[0]))
+    s = eng.metrics.summary()
+    assert s["launch_retries"] == 0 and s["failed"] == 0
+
+
+def test_unavailable_device_error_is_retried(tiny_parts, ref_streams):
+    eng = _build(tiny_parts)
+    _fail_nth_launch(eng, 3, "UNAVAILABLE: injected transfer drop")
+    s = _drain(eng, _prompts(tiny_parts[0]))
+    assert s["launch_retries"] == 1 and s["failed"] == 0
+    assert _streams(eng) == ref_streams
+
+
 def test_escalation_storm_forces_routing_not_tokens(tiny_parts,
                                                     ref_streams):
     # δ=0 never escalates; the storm forces every gate decision up.

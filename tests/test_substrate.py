@@ -1,6 +1,7 @@
 """Substrate tests: optimizers, data pipeline, checkpointing, classifier."""
 import os
 import tempfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,24 @@ def test_bigram_lm_has_learnable_structure():
     assert max(len(v) for v in succ.values()) <= 2
 
 
+@pytest.mark.parametrize("vocab", [64, 262144])
+def test_bigram_lm_trigram_successor_is_a_function(vocab):
+    """With only trigram steps, each token is fixed by the two before it
+    — from a dense table at small vocabularies, from a hash at LLM ones
+    (where the table would not fit in memory)."""
+    toks = bigram_lm(num_seqs=64, seq_len=32, vocab=vocab,
+                     trigram_frac=1.0, seed=3)
+    assert toks.shape == (64, 32) and toks.dtype == np.int32
+    assert 0 <= toks.min() and toks.max() < vocab
+    nxt = {}
+    for row in toks:
+        for a, b, c in zip(row[:-2], row[1:-1], row[2:]):
+            assert nxt.setdefault((int(a), int(b)), int(c)) == int(c)
+    np.testing.assert_array_equal(
+        toks, bigram_lm(num_seqs=64, seq_len=32, vocab=vocab,
+                        trigram_frac=1.0, seed=3))
+
+
 def test_teacher_task_capacity_headroom():
     ds, info = teacher_task(num_samples=2000, return_info=True)
     assert 0.5 < info["bayes_acc"] <= 1.0
@@ -106,3 +125,26 @@ def test_checkpoint_missing_key_raises():
         ckpt.save(path, tree)
         with pytest.raises(KeyError):
             ckpt.load(path, like=bigger)
+
+
+@pytest.mark.parametrize("env", ["/elsewhere/jax-cache", None])
+def test_compile_cache_dir(monkeypatch, env):
+    """An exported JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the
+    cache goes to the fixed `.jax_cache` at the root of the checkout."""
+    from repro.launch import compile_cache
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        used = compile_cache.use_compile_cache()
+        after = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if env is None:
+        assert used == after == str(compile_cache.REPO_CACHE_DIR)
+        assert compile_cache.REPO_CACHE_DIR.parent == \
+            Path(__file__).resolve().parents[1]
+    else:
+        assert used == env and after == before
