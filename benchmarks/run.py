@@ -11,9 +11,8 @@ import sys
 import time
 import traceback
 
-from benchmarks import (decode_attention, fig3_splitting, fig4_params,
-                        fig5_histograms, roofline, serving_throughput,
-                        step_launches, table1_models, table23_cascade,
+from benchmarks import (fig3_splitting, fig4_params, fig5_histograms,
+                        roofline, table1_models, table23_cascade,
                         table4_three_element, table5_hard_task,
                         table6_accuracy_effect, table7_llm_cascade)
 from repro.launch.compile_cache import use_compile_cache
@@ -29,9 +28,6 @@ ARTIFACTS = {
     "fig4": fig4_params.main,
     "fig5": fig5_histograms.main,
     "roofline": roofline.main,
-    "serving": serving_throughput.main,
-    "decode_attn": decode_attention.main,
-    "step_launches": step_launches.main,
 }
 
 

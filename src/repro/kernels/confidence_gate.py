@@ -128,6 +128,7 @@ def confidence_gate(logits, *, interpret: bool = False):
             pltpu.VMEM((ROW_TILE, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="confidence_gate",
     )(x)
 
     def cut(a):

@@ -267,5 +267,6 @@ def ragged_attention(q, k_pages, v_pages, page_table, q_start, q_len,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((W, KV, G, hd), q.dtype),
         interpret=interpret,
+        name="ragged_attention",
     )(page_table, wt, wr, wf, wl, row_start,
       q_start.astype(jnp.int32), q_len.astype(jnp.int32), *operands)

@@ -182,7 +182,7 @@ class StepPlan:
     interface:
 
     * **ragged flat** (the default on paged attention-only tiers): one
-      :meth:`_TierRuntime.run_ragged` launch consumes the flat packing —
+      :meth:`_TierRuntime.call_flat` launch consumes the flat packing —
       every live row's tokens concatenated into ``flat_tokens [1, W]``
       (W a bucketed power-of-two width), so the tick's compute is
       O(live tokens) end-to-end.
@@ -234,6 +234,20 @@ class StepPlan:
     def live_tokens(self) -> int:
         """Real tokens this tick computes (prefill chunks + decode)."""
         return int(self.q_len.sum())
+
+    def work(self) -> dict:
+        """A ragged launch's work, as the tracer's ``launch`` span carries
+        it: live ``tokens``; ``kv_read``, the keys the live rows read once
+        per attention layer (Σ q_start + q_len); ``kv_pairs``, the
+        query-key pairs attended (each live token's causal context);
+        ``emitted``, the rows whose logits are used (finishing, decode and
+        verify rows)."""
+        q = self.q_len.astype(np.int64)
+        start = self.q_start.astype(np.int64)
+        return {"tokens": int(q.sum()),
+                "kv_read": int((start + q)[q > 0].sum()),
+                "kv_pairs": int((q * start + q * (q + 1) // 2).sum()),
+                "emitted": len(self.finishing) + len(self.decode_rows)}
 
 
 class _TierRuntime:
@@ -554,30 +568,33 @@ class _TierRuntime:
         return jax.device_put(arr, NamedSharding(self.mesh,
                                                  PartitionSpec()))
 
-    def run_ragged(self, flat_tokens, flat_pos, qlen, qstart):
-        """The ragged flat token-batch launch: ONE compiled program at a
-        bucketed flat width serves the tick's live tokens — each token
-        scatters KV through and attends its owning row's pages, so the
-        program's compute is O(live tokens), not O(capacity * width)."""
+    def stage_flat(self, flat_tokens, flat_pos, qlen, qstart,
+                   draft_len=None):
+        """The host->device puts of one ragged launch, in the step's
+        argument order: flat tokens, flat positions, page table,
+        ``q_len``, ``q_start``, and with ``draft_len [capacity]`` the
+        speculative step's per-row draft budget."""
         self.launched_widths.add(int(np.asarray(flat_tokens).shape[1]))
-        with self._ctx():
-            return self.ragged_fn(
-                self.params, self.put_flat(flat_tokens), self.pool.cache,
-                self.put_flat(flat_pos), self.page_table_device(),
-                self.put_rows(qlen), self.put_rows(qstart))
+        staged = (self.put_flat(flat_tokens), self.put_flat(flat_pos),
+                  self.page_table_device(), self.put_rows(qlen),
+                  self.put_rows(qstart))
+        if draft_len is not None:
+            staged += (self.put_rows(draft_len),)
+        return staged
 
-    def run_spec(self, flat_tokens, flat_pos, qlen, qstart, draft_len):
-        """The speculative ragged launch (``speculation_k > 0``): the
-        same flat token-batch contract as :meth:`run_ragged`, plus the
-        per-row draft budget ``draft_len [capacity]`` driving the fused
-        draft scan.  Still ONE compiled program per tier per tick."""
-        self.launched_widths.add(int(np.asarray(flat_tokens).shape[1]))
+    def call_flat(self, spec: bool, staged):
+        """The ragged flat token-batch launch on :meth:`stage_flat`'s
+        arguments: ONE compiled program at a bucketed flat width serves
+        the tick's live tokens — each token scatters KV through and
+        attends its owning row's pages, so the program's compute is
+        O(live tokens), not O(capacity * width).  With ``spec`` it is the
+        speculative step (``speculation_k > 0``, ``staged`` holding the
+        draft budget), whose fused draft scan runs in the same one
+        program per tier per tick."""
+        fn = self.spec_fn if spec else self.ragged_fn
+        tokens, pos, *rest = staged
         with self._ctx():
-            return self.spec_fn(
-                self.params, self.put_flat(flat_tokens), self.pool.cache,
-                self.put_flat(flat_pos), self.page_table_device(),
-                self.put_rows(qlen), self.put_rows(qstart),
-                self.put_rows(draft_len))
+            return fn(self.params, tokens, self.pool.cache, pos, *rest)
 
     def page_table_device(self, mask_rows: Sequence[int] = ()):
         """Device page tables; ``mask_rows`` (rows mid-prefill during a
@@ -1644,26 +1661,21 @@ class CascadeEngine:
                     and not plan.draft_rows:
                 return 0                # every live row stalled
             t0 = tr.now_us() if tr is not None else 0.0
+            puts: List[tuple] = []      # traced put interval per attempt
             kind = ("run_spec" if use_spec
                     else "run_ragged" if rt.ragged else "run_mixed")
             try:
                 with obs.annotation(f"{kind}/{rt.spec.name}",
                                     self.profile_annotations):
-                    if use_spec:
+                    if rt.ragged:
                         out = self._launch(
                             tier, kind,
-                            lambda p=plan: rt.run_spec(
-                                p.flat_tokens, p.flat_pos, p.q_len,
-                                p.q_start, p.draft_len))
+                            lambda p=plan: self._run_flat(rt, p, use_spec,
+                                                          puts))
                         tok, conf = out[0], out[1]
-                        spec_out = out[2:7]
-                        cache = out[7]
-                    elif rt.ragged:
-                        tok, conf, cache = self._launch(
-                            tier, kind,
-                            lambda p=plan: rt.run_ragged(
-                                p.flat_tokens, p.flat_pos, p.q_len,
-                                p.q_start))
+                        if use_spec:
+                            spec_out = out[2:7]
+                        cache = out[-1]
                     else:
                         tok, conf, cache = self._launch(
                             tier, kind,
@@ -1686,10 +1698,20 @@ class CascadeEngine:
             break
         if tr is not None:
             # async dispatch: this phase is host-side launch cost (incl.
-            # put_rows transfers); device wait shows under device_get
-            tr.phase("launch", tier, t0, tick=self.tick_id,
-                     kind="ragged" if rt.ragged else "mixed",
-                     width=plan.flat_width if rt.ragged else plan.width)
+            # put_rows transfers); device wait shows under device_get.
+            # Each attempt's puts follow as nested ``put`` phases, recorded
+            # after the launch so the track's spans stay in start order.
+            # The launch ends before its work is counted
+            t1 = tr.now_us()
+            if rt.ragged:
+                tr.phase("launch", tier, t0, t1, tick=self.tick_id,
+                         kind="ragged", width=plan.flat_width,
+                         **plan.work())
+            else:
+                tr.phase("launch", tier, t0, t1, tick=self.tick_id,
+                         kind="mixed", width=plan.width)
+            for p0, p1 in puts:
+                tr.phase("put", tier, p0, p1, tick=self.tick_id)
         self.metrics.record_launches(tier, 1)
         # exact live-vs-processed token accounting: the ragged program
         # computes flat_width token slots (bucket padding only), the
@@ -1781,6 +1803,19 @@ class CascadeEngine:
                 req.draft_tokens = [int(x) for x in dtok[s, :keep]]
                 req.draft_confs = [float(x) for x in dconf[s, :keep]]
         return len(plan.decode_rows)
+
+    def _run_flat(self, rt: _TierRuntime, plan: StepPlan, spec: bool,
+                  puts: list):
+        """One attempt at a ragged (``spec``: speculative) launch: stage
+        the plan's host->device puts, then call the jitted step.  Traced,
+        the interval of the puts is appended to ``puts``."""
+        tr = self.tracer
+        t0 = tr.now_us() if tr is not None else 0.0
+        staged = rt.stage_flat(plan.flat_tokens, plan.flat_pos, plan.q_len,
+                               plan.q_start, plan.draft_len if spec else None)
+        if tr is not None:
+            puts.append((t0, tr.now_us()))
+        return rt.call_flat(spec, staged)
 
     def _exec_split(self, tier: int, rt: _TierRuntime,
                     plan: StepPlan, now: float) -> int:
@@ -2150,11 +2185,9 @@ class CascadeEngine:
                 zr = np.zeros(rt.capacity, np.int32)
                 for w in rt.flat_buckets:
                     z = np.zeros((1, w), np.int32)
-                    if rt.spec_fn is not None:
-                        out = rt.run_spec(z, z, zr, zr, zr)
-                        rt.pool.cache = out[-1]
-                    else:
-                        _, _, rt.pool.cache = rt.run_ragged(z, z, zr, zr)
+                    spec = rt.spec_fn is not None
+                    rt.pool.cache = rt.call_flat(spec, rt.stage_flat(
+                        z, z, zr, zr, zr if spec else None))[-1]
                 rt.warmed_widths = set(rt.flat_buckets)
                 rt.launched_widths = set()
                 continue

@@ -58,7 +58,7 @@ def test_absolute_output_paths_are_ignored():
 
 
 def test_root_and_src_relative_paths_resolve():
-    text = ("`README.md` `benchmarks/serving_throughput.py` "
+    text = ("`README.md` `benchmarks/run.py` "
             "`repro/serving/engine.py` `kernels/prefill_attention.py`")
     assert check_docs.check_text(text, ROOT) == []
 

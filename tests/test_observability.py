@@ -249,18 +249,35 @@ def _submit_all(eng, cfg, n=6):
 
 @pytest.fixture(scope="module")
 def traced_run(cfg, params, tmp_path_factory):
+    """The traced run, and what each ragged launch staged: the runtime,
+    and per live row its flat positions and its request's prompt length."""
+    from repro.serving.engine import _TierRuntime
+    stage, staged = _TierRuntime.stage_flat, []
+
+    def spy(rt, flat_tokens, flat_pos, qlen, qstart, *rest):
+        rows, o = [], 0
+        for s, n in enumerate(np.asarray(qlen).tolist()):
+            if n:
+                rows.append((np.asarray(flat_pos)[0, o:o + n].tolist(),
+                             rt.slot_req[s].prompt_tokens))
+                o += n
+        staged.append((rt, rows))
+        return stage(rt, flat_tokens, flat_pos, qlen, qstart, *rest)
+
     tr = Tracer()
     eng = _engine(cfg, params, tracer=tr)
     _submit_all(eng, cfg)
     snaps = []
-    summary = eng.run(metrics_interval=3.0, on_snapshot=snaps.append)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(_TierRuntime, "stage_flat", spy)
+        summary = eng.run(metrics_interval=3.0, on_snapshot=snaps.append)
     path = tmp_path_factory.mktemp("trace") / "trace.json"
     tr.export(str(path))
-    return eng, summary, tr, snaps, path
+    return eng, summary, tr, snaps, path, staged
 
 
 def test_traced_run_matches_untraced(cfg, params, traced_run):
-    eng_t, summary_t, _, _, _ = traced_run
+    eng_t, summary_t, *_ = traced_run
     eng = _engine(cfg, params, tracer=None)
     _submit_all(eng, cfg)
     summary = eng.run()
@@ -275,12 +292,12 @@ def test_traced_run_matches_untraced(cfg, params, traced_run):
 
 
 def test_traced_run_emits_schema_valid_spans(traced_run):
-    eng, summary, tr, _, path = traced_run
+    eng, summary, tr, _, path, _ = traced_run
     trace = json.loads(path.read_text())
     assert check_trace.validate_trace(trace) == []
     evs = trace["traceEvents"]
     phases = {e["name"] for e in evs if e["ph"] == "X"}
-    assert {"tick", "admit", "plan", "launch",
+    assert {"tick", "admit", "plan", "launch", "put",
             "device_get", "finish"} <= phases
     states = {e["name"] for e in evs if e["ph"] == "b"}
     assert {"QUEUED", "PREFILL", "DECODE", "ESCALATED"} <= states
@@ -295,8 +312,41 @@ def test_traced_run_emits_schema_valid_spans(traced_run):
     assert any(n.startswith("live rows/") for n in counters)
 
 
+def test_ragged_launches_carry_their_work(traced_run):
+    """Each ragged ``launch`` span's work counts, recomputed from the
+    positions its live rows staged: tokens, keys read (each row's
+    context once), query-key pairs (each token's causal context), and
+    the rows whose logits are used (those reaching past the prompt)."""
+    eng, _, tr, _, _, staged = traced_run
+    launches = [e for e in tr.events()
+                if e["ph"] == "X" and e["name"] == "launch"]
+    assert launches and {e["args"]["kind"] for e in launches} == {"ragged"}
+    for t, rt in enumerate(eng.runtimes):
+        got = [e["args"] for e in launches if e["tid"] == t]
+        want = [rows for r, rows in staged if r is rt and rows]
+        assert len(got) == len(want) > 0
+        for args, rows in zip(got, want):
+            assert args["tokens"] == sum(len(p) for p, _ in rows)
+            assert args["kv_read"] == sum(p[-1] + 1 for p, _ in rows)
+            assert args["kv_pairs"] == sum(q + 1 for p, _ in rows for q in p)
+            assert args["emitted"] == sum(p[-1] + 1 >= n for p, n in rows)
+
+
+def test_each_launch_holds_one_put(traced_run):
+    _, _, tr, _, _, _ = traced_run
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    launches = [e for e in spans if e["name"] == "launch"]
+    puts = [e for e in spans if e["name"] == "put"]
+    assert launches and len(puts) == len(launches)
+    for ln in launches:
+        inside = [p for p in puts if p["tid"] == ln["tid"]
+                  and ln["ts"] <= p["ts"]
+                  and p["ts"] + p["dur"] <= ln["ts"] + ln["dur"]]
+        assert len(inside) == 1, ln
+
+
 def test_escalation_outcome_calibration(traced_run):
-    _, summary, _, _, _ = traced_run
+    _, summary, *_ = traced_run
     cal = summary["gate_calibration"]
     assert len(cal) == 1
     g = cal[0]
@@ -321,7 +371,7 @@ def test_no_escalation_means_no_outcomes(cfg, params):
 
 
 def test_tick_durations_under_virtual_clock(traced_run):
-    eng, summary, _, _, _ = traced_run
+    eng, summary, *_ = traced_run
     # VirtualClock advances exactly 1.0 per engine step
     assert summary["tick_duration_p50"] == 1.0
     assert summary["tick_duration_max"] == 1.0
@@ -330,7 +380,7 @@ def test_tick_durations_under_virtual_clock(traced_run):
 
 
 def test_metrics_interval_snapshots(traced_run):
-    _, summary, _, snaps, _ = traced_run
+    _, summary, _, snaps, *_ = traced_run
     assert snaps, "run(metrics_interval=...) emitted no snapshots"
     assert all(s["t"] <= summary["steps"] + 1 for s in snaps)
     ts = [s["t"] for s in snaps]

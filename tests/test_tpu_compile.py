@@ -20,6 +20,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
     SingleDeviceSharding
 
+from bench.harness import tracered
 from repro.kernels import ops
 from repro.kernels.confidence_gate import confidence_gate
 from repro.kernels.paged_attention import paged_attention
@@ -46,10 +47,21 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
-def _compile(fn, sharding, *shapes):
+def _kernels_named(compiled):
+    """What the benchmark's trace reduction calls the program's Mosaic
+    kernels: ``tracered.op_key`` of each kernel instruction's name, which
+    is the name a device trace gives the kernel's events."""
+    return {tracered.op_key(line.split(" = ", 1)[0].split()[-1], {})
+            for line in compiled.as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def _compile(fn, sharding, *shapes, kernel=None):
     args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()      # the Mosaic kernel
+    if kernel is not None:
+        assert kernel in _kernels_named(compiled)
     return compiled
 
 
@@ -58,7 +70,7 @@ def _compile(fn, sharding, *shapes):
 def test_confidence_gate_compiles(one_chip, vocab, rows):
     """Rows past one 8-row tile were refused before the blocks were 2-D."""
     _compile(lambda x: confidence_gate(x, interpret=False), one_chip,
-             ((rows, vocab), jnp.bfloat16))
+             ((rows, vocab), jnp.bfloat16), kernel="confidence_gate")
 
 
 @pytest.mark.parametrize("tier,kv,g,hd,window", HEADS)
@@ -71,7 +83,7 @@ def test_ragged_attention_compiles(one_chip, tier, kv, g, hd, window):
              ((BLOCKS, BLOCK, kv, hd), jnp.bfloat16),
              ((BLOCKS, BLOCK, kv, hd), jnp.bfloat16),
              ((SLOTS, PAGES), jnp.int32), ((SLOTS,), jnp.int32),
-             ((SLOTS,), jnp.int32))
+             ((SLOTS,), jnp.int32), kernel="ragged_attention")
 
 
 @pytest.mark.parametrize("tier,kv,g,hd,window", HEADS)
@@ -109,3 +121,4 @@ def test_ragged_attention_compiles_on_a_tier_mesh(topo, tier, kv, g, hd,
         compiled = jax.jit(lambda *a: ops.ragged_attention(
             *a, window=window, interpret=False)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert "ragged_attention" in _kernels_named(compiled)
