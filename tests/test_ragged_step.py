@@ -38,8 +38,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _flat_case(rng, B, C, KV, G, hd, P, bs, qlens, quant=False,
-               window=None):
-    """Build a flat-packed batch + pool and return (kernel, oracle)."""
+               window=None, q_start=None):
+    """Build a flat-packed batch + pool and return (kernel, oracle).
+    The page table is a shuffled, non-contiguous draw of blocks; each
+    row's first query position is drawn unless ``q_start`` gives it."""
     N = B * P + 1
     if quant:
         kp = jnp.asarray(rng.integers(-127, 128, (N, bs, KV, hd)), jnp.int8)
@@ -53,8 +55,9 @@ def _flat_case(rng, B, C, KV, G, hd, P, bs, qlens, quant=False,
     pt = jnp.asarray(
         rng.permutation(np.arange(1, N))[:B * P].reshape(B, P), jnp.int32)
     q_len = np.asarray(qlens, np.int32)
-    q_start = np.asarray([int(rng.integers(0, P * bs - C))
-                          for _ in range(B)], np.int32)
+    if q_start is None:
+        q_start = [int(rng.integers(0, P * bs - C)) for _ in range(B)]
+    q_start = np.asarray(q_start, np.int32)
     q_rows = rng.standard_normal((B, C, KV, G, hd)).astype(np.float32)
     total = int(q_len.sum())
     W = max(8, 1 << (max(total, 1) - 1).bit_length())
@@ -72,32 +75,50 @@ def _flat_case(rng, B, C, KV, G, hd, P, bs, qlens, quant=False,
     return np.asarray(got), np.asarray(want), total
 
 
-@pytest.mark.parametrize("qlens", [
-    [3, 0, 16, 1, 1, 7, 0, 5],      # arbitrary mix incl. stalls
-    [1] * 8,                        # decode-only tick
-    [16] * 8,                       # full prefill tick
-    [0] * 8,                        # all rows idle
-    [16, 0, 0, 0, 0, 0, 0, 0],      # single live row
-    [8, 8, 0, 0, 0, 0, 0, 0],       # total exactly a bucket boundary
-])
-def test_ragged_kernel_matches_oracle(qlens):
+# Shapes of the oracle cases: the base batch, then cases for the page
+# groups (``ppg = 128 // bs`` pages, clamped to P): rows longer than two
+# groups, contexts ending mid-group and on a group edge, starcoder2's
+# head layout, and a prefill row spanning several tiles beside decode.
+BASE = dict(B=8, C=16, KV=2, G=2, hd=32, P=5, bs=16)
+LONG = dict(BASE, B=4, P=20)                    # 3 groups of 8 pages
+
+
+@pytest.mark.parametrize("qlens,shape", [
+    ([3, 0, 16, 1, 1, 7, 0, 5], {}),      # arbitrary mix incl. stalls
+    ([1] * 8, {}),                        # decode-only tick
+    ([16] * 8, {}),                       # full prefill tick
+    ([0] * 8, {}),                        # all rows idle
+    ([16, 0, 0, 0, 0, 0, 0, 0], {}),      # single live row
+    ([8, 8, 0, 0, 0, 0, 0, 0], {}),       # total exactly a bucket boundary
+    ([1, 16, 3, 1], LONG),
+    # last keys 127, 128, 200 and 127: on a group edge, one past it,
+    # mid-group, and a 16-query tile ending on the edge
+    ([1, 1, 1, 16], dict(LONG, q_start=[127, 128, 200, 112])),
+    ([1, 5, 0, 1, 2, 1, 1, 1], dict(BASE, KV=4, G=9)),
+    ([40, 1, 1, 1], dict(LONG, C=48, q_start=[200, 40, 300, 7])),
+], ids=[f"qlens{i}" for i in range(6)] + [
+    "rows_past_two_groups", "contexts_at_group_edges",
+    "starcoder2_heads", "prefill_across_tiles_with_decode"])
+def test_ragged_kernel_matches_oracle(qlens, shape):
     """Rows with ANY q_len in [0, C] pack into one flat batch; outputs
     match the jnp oracle per token, and padding slots are exact zero."""
     rng = np.random.default_rng(0)
-    got, want, total = _flat_case(rng, B=8, C=16, KV=2, G=2, hd=32,
-                                  P=5, bs=16, qlens=qlens)
+    got, want, total = _flat_case(rng, **{**BASE, **shape}, qlens=qlens)
     np.testing.assert_allclose(got[:total], want[:total],
                                rtol=2e-5, atol=2e-6)
     np.testing.assert_array_equal(got[total:], 0.0)
 
 
-@pytest.mark.parametrize("quant,window", [(True, None), (False, 24),
-                                          (True, 16)])
-def test_ragged_kernel_int8_and_window(quant, window):
+@pytest.mark.parametrize("quant,window,shape", [
+    (True, None, BASE), (False, 24, BASE), (True, 16, BASE),
+    (False, 200, LONG),                   # window opens mid-group
+    (True, 100, LONG),
+], ids=["True-None", "False-24", "True-16", "window_mid_group",
+        "int8_window_long_rows"])
+def test_ragged_kernel_int8_and_window(quant, window, shape):
     rng = np.random.default_rng(7)
-    qlens = rng.integers(0, 17, 8)
-    got, want, total = _flat_case(rng, B=8, C=16, KV=2, G=2, hd=32,
-                                  P=5, bs=16, qlens=qlens, quant=quant,
+    qlens = rng.integers(0, 17, shape["B"])
+    got, want, total = _flat_case(rng, **shape, qlens=qlens, quant=quant,
                                   window=window)
     np.testing.assert_allclose(got[:total], want[:total],
                                rtol=1e-4, atol=1e-5)

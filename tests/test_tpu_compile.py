@@ -86,6 +86,31 @@ def test_ragged_attention_compiles(one_chip, tier, kv, g, hd, window):
              ((SLOTS,), jnp.int32), kernel="ragged_attention")
 
 
+# The benchmark's chat cell: phi4-mini-3.8b -> starcoder2-7b (KV 4, G 9,
+# head 128), 18 rows of 72 pages, 1297 blocks of 16, at its smallest and
+# largest flat widths.
+CELL_SLOTS, CELL_PAGES, CELL_BLOCKS = 18, 72, 1297
+CELL_HEADS = [("phi4-mini-3.8b", 8, 3, 128), ("starcoder2-7b", 4, 9, 128)]
+
+
+@pytest.mark.parametrize("width", [16, 1152])
+@pytest.mark.parametrize("tier,kv,g,hd", CELL_HEADS)
+def test_ragged_attention_compiles_at_the_chat_cells_shapes(
+        one_chip, tier, kv, g, hd, width):
+    """The page-group double buffers fit the chip's VMEM at the cell's
+    widths, and the kernel keeps the name the benchmark's trace
+    reduction reads its share and roofline under."""
+    _compile(lambda q, k, v, pt, qs, ql: ragged_attention(
+                 q, k, v, pt, qs, ql, interpret=False),
+             one_chip,
+             ((width, kv, g, hd), jnp.bfloat16),
+             ((CELL_BLOCKS, BLOCK, kv, hd), jnp.bfloat16),
+             ((CELL_BLOCKS, BLOCK, kv, hd), jnp.bfloat16),
+             ((CELL_SLOTS, CELL_PAGES), jnp.int32),
+             ((CELL_SLOTS,), jnp.int32), ((CELL_SLOTS,), jnp.int32),
+             kernel="ragged_attention")
+
+
 @pytest.mark.parametrize("tier,kv,g,hd,window", HEADS)
 def test_paged_attention_compiles(one_chip, tier, kv, g, hd, window):
     """phi4's KV=8 was refused while each block held one KV head."""
