@@ -5,11 +5,14 @@
     bench/traffic/<t>.json    a traffic mix
     bench/cells/<w>.json      a cell's operator settings, rate and limits
     bench/metrics/<m>.py      the reader of per-layer metric ``m``
+    bench/harness/arch/<a>.py a tier's architecture (the ``arch`` of its
+                              entry in the configuration; ``dense_gqa``
+                              without one): layout, reference, counts
 
 A metric ``a.b`` whose quantity is split across cells is read by
 ``metrics/a.b.py`` if there is one, else by ``metrics/a.py``.  Adding a
-cell, mix, configuration or metric adds files and entries; no file that
-is there changes.
+cell, mix, configuration, architecture or metric adds files and entries;
+no file that is there changes.
 """
 from __future__ import annotations
 
@@ -20,24 +23,9 @@ from dataclasses import dataclass
 from pathlib import Path
 from typing import List
 
-ROOT = Path(__file__).resolve().parents[2]
+from bench.harness import arch
 
-# published (Hugging Face config.json) key -> how the repo's ModelConfig
-# states it; checked after the depth cut, so a file cannot claim widths
-# the program does not run
-_HF_KEYS = {
-    "hidden_size": lambda c: c.d_model,
-    "intermediate_size": lambda c: c.period[0].ffn.d_ff,
-    "num_attention_heads": lambda c: c.num_heads,
-    "num_key_value_heads": lambda c: c.num_kv_heads,
-    "head_dim": lambda c: c.head_dim,
-    "num_hidden_layers": lambda c: c.num_layers,
-    "vocab_size": lambda c: c.vocab_size,
-    "tie_word_embeddings": lambda c: c.tie_embeddings,
-    "rope_theta": lambda c: c.rope_theta,
-    "rms_norm_eps": lambda c: c.norm_eps,
-    "norm_epsilon": lambda c: c.norm_eps,
-}
+ROOT = Path(__file__).resolve().parents[2]
 
 
 @dataclass
@@ -99,18 +87,23 @@ def load_reader(metric: str, root: Path = ROOT):
     return mod.read
 
 
-def tier_config(tier: dict):
+def tier_config(tier: dict, root: Path = ROOT):
     """The tier's ``ModelConfig``: the registry entry it names, with the
     file's ``registry_replace`` applied (the depth cut), registered under
-    the file's ``served_name`` and checked against the file's
-    published-key values."""
+    the file's ``served_name``, bound to the tier's architecture module,
+    and checked against the file's published-key values that the
+    module's ``HF_KEYS`` names."""
     from repro.configs.base import get_config, register
 
+    module = arch.load(tier.get("arch", arch.DEFAULT), root)
     base = get_config(tier["registry"], tier.get("registry_variant"))
     cfg = dataclasses.replace(base, name=tier["served_name"],
                               **tier.get("registry_replace", {}))
+    keys = module.HF_KEYS
     for key, want in tier["config"].items():
-        if key in _HF_KEYS and _HF_KEYS[key](cfg) != want:
-            raise ValueError(f"{cfg.name}: {key} is {_HF_KEYS[key](cfg)} "
+        if key in keys and keys[key](cfg) != want:
+            raise ValueError(f"{cfg.name}: {key} is {keys[key](cfg)} "
                              f"in the program, {want} in the file")
-    return register(cfg)
+    cfg = register(cfg)
+    arch.bind(cfg, module)
+    return cfg

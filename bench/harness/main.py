@@ -147,7 +147,7 @@ def build(cell, seed: int, trace: bool) -> SimpleNamespace:
     from repro.serving import Tracer
 
     use_cache(cell.root)
-    cfgs = [cells.tier_config(t) for t in cell.tiers]
+    cfgs = [cells.tier_config(t, cell.root) for t in cell.tiers]
     dtype = jnp.dtype(cell.config["dtype"])
     made = {c.name: weights.make(c, seed, i, dtype)
             for i, c in enumerate(cfgs)}

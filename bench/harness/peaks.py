@@ -1,4 +1,5 @@
-"""Published peaks per device, and the model FLOPs the benchmark counts.
+"""Published peaks per device, and the model FLOPs the benchmark counts
+from the counts of each tier's architecture (``bench/harness/arch/``).
 
 Peaks are keyed by ``jax.Device.device_kind``.  A device that is not in
 the table is an error, never a default: a utilization against a guessed
@@ -7,6 +8,8 @@ peak is no measurement.
 from __future__ import annotations
 
 from dataclasses import dataclass
+
+from bench.harness import arch
 
 
 @dataclass(frozen=True)
@@ -35,32 +38,16 @@ def peak_for(device_kind: str) -> Peak:
                        f"known: {sorted(PEAKS)}") from None
 
 
-def matmul_weights_per_layer(cfg) -> int:
-    """Weights one token multiplies through in one decoder layer of a
-    dense GQA model (attention projections and the feed-forward), as the
-    repo's blocks lay them out: no biases."""
-    d, h, kv, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    attn = d * h * hd * 2 + d * kv * hd * 2          # wq, wo, wk, wv
-    total = 0
-    for layer in cfg.layers:
-        f = layer.ffn
-        if layer.mixer.kind != "attn" or f.kind != "dense":
-            raise ValueError(f"{cfg.name}: only dense attention layers are "
-                             "counted here")
-        total += attn + (3 if f.act == "swiglu" else 2) * d * f.d_ff
-    return total // cfg.num_layers
-
-
 def token_flops(cfg, context: int, emits: bool) -> float:
     """Model FLOPs of one token at 0-based position ``context - 1``: two
-    per weight it multiplies through in every layer, the attention
-    scores and weighted values over its ``context`` keys
-    (``4 * heads * head_dim`` per key and layer), and the output
-    projection when its logits are used (``emits``)."""
-    per_layer = 2 * matmul_weights_per_layer(cfg) \
-        + 4 * cfg.num_heads * cfg.head_dim * context
+    per weight it multiplies through in its layers, the attention scores
+    and weighted values over its ``context`` keys (the architecture's
+    FLOPs per query-key pair), and the output projection when its logits
+    are used (``emits``)."""
+    a = arch.of(cfg)
     head = 2 * cfg.d_model * cfg.vocab_size if emits else 0
-    return float(cfg.num_layers * per_layer + head)
+    return float(2 * a.matmul_weights(cfg) + a.pair_flops(cfg) * context
+                 + head)
 
 
 def span_flops(cfg, start: int, stop: int, emitted: int) -> float:
@@ -71,7 +58,6 @@ def span_flops(cfg, start: int, stop: int, emitted: int) -> float:
     if n <= 0:
         return 0.0
     keys = (stop * (stop + 1) - start * (start + 1)) // 2   # sum of p + 1
-    per_layer = 2 * matmul_weights_per_layer(cfg) * n \
-        + 4 * cfg.num_heads * cfg.head_dim * keys
-    return float(cfg.num_layers * per_layer
+    a = arch.of(cfg)
+    return float(2 * a.matmul_weights(cfg) * n + a.pair_flops(cfg) * keys
                  + 2 * cfg.d_model * cfg.vocab_size * emitted)

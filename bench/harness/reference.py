@@ -1,14 +1,14 @@
-"""Plain float32 reference of the dense GQA/RoPE decoder both tiers use.
+"""Plain float32 reference of the tiers, around each architecture's
+forward pass (``bench/harness/arch/<name>.py``).
 
 Straight ``jax.numpy`` with ``default_matmul_precision("highest")``: no
 kernel, cache, paging or batching, and nothing imported from the
-program.  It reads the weights the benchmark made (``weights.py``) and
-casts one layer's bfloat16 weights to float32 at a time, so it fits
-beside them on a chip whose cache arenas have been freed.
-
-It follows the model as the repo's blocks implement it
-(``models/blocks.py``); each configuration file lists where that departs
-from the published model (norms, biases, rotary fraction, rope scaling).
+program.  It reads the weights the benchmark made (``weights.py``); an
+architecture's forward pass casts one layer's bfloat16 weights to
+float32 at a time, so it fits beside them on a chip whose cache arenas
+have been freed.  This module holds what every architecture shares: the
+weight cast, the norm, the final norm and the output projection in
+blocks of the vocabulary, and the scores of a served answer.
 
 ``precision="fp8"`` is the control: every matmul weight rounded to
 float8 e4m3 with a scale per output channel, the step below bfloat16
@@ -16,12 +16,13 @@ that would tempt a later change.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.harness import arch
 
 PAD = 256            # sequences are padded to a multiple of this
 VOCAB_BLOCK = 20000  # output-projection rows cast to float32 at a time
@@ -48,53 +49,6 @@ def _rmsnorm(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
-    """Rotate every pair (i, i + d/2) of the head dim by pos * theta^(-2i/d)."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos.astype(jnp.float32)[:, None] * inv            # [S, d/2]
-    c, s = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-
-
-@partial(jax.jit, static_argnames=("heads", "kv_heads", "head_dim", "act",
-                                   "theta", "eps", "precision"))
-def _layer(x, lp, *, heads, kv_heads, head_dim, act, theta, eps, precision):
-    with jax.default_matmul_precision("highest"):
-        s = x.shape[0]
-        w = lambda name, ax=0: _weight(lp[name], precision, ax)  # noqa: E731
-        pos = jnp.arange(s)
-        h = _rmsnorm(x, lp["norm1"], eps)
-        q = (h @ w("wq")).reshape(s, heads, head_dim)
-        k = (h @ w("wk")).reshape(s, kv_heads, head_dim)
-        v = (h @ w("wv")).reshape(s, kv_heads, head_dim)
-        q, k = _rope(q, pos, theta), _rope(k, pos, theta)
-        group = heads // kv_heads
-        k = jnp.repeat(k, group, axis=1)                     # head h -> h // G
-        v = jnp.repeat(v, group, axis=1)
-
-        def attend(block):                # one block of PAD queries
-            qb, qpos = block
-            scores = jnp.einsum("qhd,khd->hqk", qb, k) / math.sqrt(head_dim)
-            causal = qpos[:, None] >= pos[None, :]
-            probs = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), -1)
-            return jnp.einsum("hqk,khd->qhd", probs, v)
-
-        att = jax.lax.map(attend, (q.reshape(s // PAD, PAD, heads, head_dim),
-                                   pos.reshape(s // PAD, PAD)))
-        att = att.reshape(s, heads * head_dim)
-        x = x + att @ w("wo")
-        h = _rmsnorm(x, lp["norm2"], eps)
-        if act == "swiglu":
-            f = jax.nn.silu(h @ w("wi0")) * (h @ w("wi1"))
-        elif act == "gelu":
-            f = jax.nn.gelu(h @ w("wi"), approximate=True)
-        else:
-            raise ValueError(act)
-        return x + f @ w("wo_ffn")
-
-
 @partial(jax.jit, static_argnames=("eps",))
 def _final(x, rows, scale, *, eps):
     return _rmsnorm(x[rows], scale, eps)
@@ -110,36 +64,9 @@ def _project(h, w, *, precision, rows_are_vocab):
 
 def logits_at(params, cfg, tokens, rows, precision: str = "f32"):
     """Float32 logits ``[len(rows), V]`` at positions ``rows`` of the
-    sequence ``tokens`` (the logits there predict the token after)."""
-    n = len(tokens)
-    step = PAD if n <= 4 * PAD else 4 * PAD   # few lengths, few compiles
-    s = -(-n // step) * step
-    toks = np.zeros(s, np.int32)
-    toks[:n] = tokens
-    x = params["embed"][jnp.asarray(toks)].astype(jnp.float32)
-    for i in range(cfg.num_periods):
-        for b, layer in enumerate(cfg.period):
-            blk = params["period"][f"block{b}"]
-            lp = {"norm1": blk["norm1"][i], "norm2": blk["norm2"][i],
-                  "wo_ffn": blk["ffn"]["wo"][i],
-                  **{k: blk["mixer"][k][i] for k in ("wq", "wk", "wv", "wo")},
-                  **{k: blk["ffn"][k][i] for k in ("wi", "wi0", "wi1")
-                     if k in blk["ffn"]}}
-            x = _layer(x, lp, heads=cfg.num_heads, kv_heads=cfg.num_kv_heads,
-                       head_dim=cfg.head_dim, act=layer.ffn.act,
-                       theta=cfg.rope_theta, eps=cfg.norm_eps,
-                       precision=precision)
-    h = _final(x, jnp.asarray(rows, np.int32), params["final_norm"],
-               eps=cfg.norm_eps)
-    head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    v = cfg.vocab_size
-    parts = []
-    for a in range(0, v, VOCAB_BLOCK):
-        b = min(a + VOCAB_BLOCK, v)
-        w = head[a:b] if cfg.tie_embeddings else head[:, a:b]
-        parts.append(_project(h, w, precision=precision,
-                              rows_are_vocab=cfg.tie_embeddings))
-    return jnp.concatenate(parts, axis=1)
+    sequence ``tokens``, by the forward pass of the tier's architecture
+    (``bench/harness/arch/``)."""
+    return arch.of(cfg).logits_at(params, cfg, tokens, rows, precision)
 
 
 @jax.jit

@@ -4,10 +4,12 @@ work the program counts on each ``launch`` span and the chip's peaks.
 The engine's Tracer puts on every ragged ``launch`` span (the ``tid`` is
 the tier) its live ``tokens``, ``kv_read`` (the keys the live rows read
 once per attention layer), ``kv_pairs`` (the query-key pairs attended)
-and ``emitted`` (the rows whose logits are used).  A launch's bound is
-the larger of its bytes over HBM bandwidth and its FLOPs over the bf16
-peak; a share of a roofline is the bounds summed over the window's
-launches, over the chip-seconds the device spent.  A speculative launch
+and ``emitted`` (the rows whose logits are used).  The tier's
+architecture (``bench/harness/arch/<name>.py``) counts a launch's bytes
+and FLOPs from that work.  A launch's bound is the larger of its bytes
+over HBM bandwidth and its FLOPs over the bf16 peak; a share of a
+roofline is the bounds summed over the window's launches, over the
+chip-seconds the device spent.  A speculative launch
 counts neither its fused draft scan's tokens nor a verify row's extra
 logit positions, so its bound is low there.
 """
@@ -15,7 +17,7 @@ from __future__ import annotations
 
 import sys
 
-from bench.harness import peaks
+from bench.harness import arch
 
 WORK = ("tokens", "kv_read", "kv_pairs", "emitted")
 _BYTES = {"bfloat16": 2, "float32": 4}
@@ -34,10 +36,6 @@ def launches(run):
             return None
         out.append((e["tid"], args))
     return out or None
-
-
-def _attention_layers(cfg) -> int:
-    return sum(layer.mixer.kind == "attn" for layer in cfg.layers)
 
 
 def counted(run) -> bool:
@@ -59,51 +57,25 @@ def _dtype_bytes(run) -> int:
     return _BYTES[run.cell.config["dtype"]]
 
 
-def _kv_bytes_per_position(cfg, dtype_bytes: int) -> int:
-    """K and V of one position in every attention layer."""
-    kv = 1 if cfg.kv_quant == "int8" else dtype_bytes
-    return _attention_layers(cfg) * cfg.num_kv_heads * cfg.head_dim * 2 * kv
-
-
-def _attention_flops(cfg, w) -> float:
-    """Scores and weighted values: ``4 * heads * head_dim`` per
-    query-key pair and attention layer."""
-    return 4.0 * cfg.num_heads * cfg.head_dim * w["kv_pairs"] \
-        * _attention_layers(cfg)
-
-
 def attention_bound_s(cfg, w, dtype_bytes: int, peak) -> float:
-    """The attention kernel of one launch, every attention layer: K and V
-    of ``kv_read`` positions read, q read and o written for ``tokens``."""
-    nbytes = w["kv_read"] * _kv_bytes_per_position(cfg, dtype_bytes) \
-        + w["tokens"] * _attention_layers(cfg) * cfg.num_heads \
-        * cfg.head_dim * 2 * dtype_bytes
-    return max(nbytes / peak.hbm_bytes_per_s,
-               _attention_flops(cfg, w) / peak.bf16_flops)
-
-
-def weight_bytes(cfg, dtype_bytes: int) -> int:
-    """The weights every launch reads whole: the head (the embedding when
-    tied), the layers' matrices and norms, the final norm.  An untied
-    embedding is read a row per token (:func:`step_bound_s`)."""
-    d, v = cfg.d_model, cfg.vocab_size
-    n = v * d + d
-    n += cfg.num_layers * (peaks.matmul_weights_per_layer(cfg) + 2 * d)
-    return n * dtype_bytes
+    """The attention kernel of one launch, every attention layer: the
+    bytes it moves, and the FLOPs of ``kv_pairs`` query-key pairs."""
+    a = arch.of(cfg)
+    return max(a.attention_bytes(cfg, w, dtype_bytes) / peak.hbm_bytes_per_s,
+               a.pair_flops(cfg) * w["kv_pairs"] / peak.bf16_flops)
 
 
 def step_bound_s(cfg, w, dtype_bytes: int, peak) -> float:
-    """The whole step of one launch: every weight read (an untied
-    embedding's rows of ``tokens``), the KV of ``kv_read`` positions read
-    and of ``tokens`` written; two FLOPs per matrix weight per live
-    token, the attention, and the output projection of the ``emitted``
-    rows."""
-    kv = _kv_bytes_per_position(cfg, dtype_bytes)
-    rows = 0 if cfg.tie_embeddings else w["tokens"] * cfg.d_model * dtype_bytes
-    nbytes = weight_bytes(cfg, dtype_bytes) + rows \
+    """The whole step of one launch: the weights it reads, the KV of
+    ``kv_read`` positions read and of ``tokens`` written; two FLOPs per
+    matrix weight per live token, the attention, and the output
+    projection of the ``emitted`` rows."""
+    a = arch.of(cfg)
+    kv = a.kv_bytes_per_position(cfg, dtype_bytes)
+    nbytes = a.weight_bytes_read(cfg, w, dtype_bytes) \
         + (w["kv_read"] + w["tokens"]) * kv
-    flops = 2.0 * peaks.matmul_weights_per_layer(cfg) * cfg.num_layers \
-        * w["tokens"] + _attention_flops(cfg, w) \
+    flops = 2.0 * a.matmul_weights(cfg) * w["tokens"] \
+        + a.pair_flops(cfg) * w["kv_pairs"] \
         + 2.0 * cfg.d_model * cfg.vocab_size * w["emitted"]
     return max(nbytes / peak.hbm_bytes_per_s, flops / peak.bf16_flops)
 

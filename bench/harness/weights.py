@@ -2,11 +2,11 @@
 
 The benchmark makes the weights and hands them to the program, so that
 the reference can read them without reading anything the program made.
-The pytree follows the program's parameter layout for dense GQA decoder
-stacks (``repro.models.params``: embedding, stacked ``period`` blocks,
-final norm, untied head); the scales are the program's own init
-(``normal(0.02)`` embedding, ``1/sqrt(fan_in)`` matrices, unit norms),
-so the logits have the scale the served path is used to.
+The pytree is the layout of the tier's architecture
+(``bench/harness/arch/<name>.py``), which follows the program's
+parameters; the scales are the program's own init (``normal(0.02)``
+embedding, ``1/sqrt(fan_in)`` matrices, unit norms), so the logits have
+the scale the served path is used to.
 """
 from __future__ import annotations
 
@@ -16,38 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-
-def layout(cfg) -> dict:
-    """``{path: (shape, init)}`` of a dense GQA decoder whose layers all
-    sit in the scanned period (``num_periods`` stacked copies of its
-    blocks)."""
-    if cfg.head or cfg.tail or cfg.frontend:
-        raise ValueError(f"{cfg.name}: only scanned periods are laid out")
-    d, v, n = cfg.d_model, cfg.vocab_size, cfg.num_periods
-    hq, hkv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
-    out = {("embed",): ((v, d), "normal:0.02"),
-           ("final_norm",): ((d,), "ones")}
-    if not cfg.tie_embeddings:
-        out[("lm_head",)] = ((d, v), "fan_in")
-    for b, layer in enumerate(cfg.period):
-        if layer.mixer.kind != "attn" or layer.ffn.kind != "dense":
-            raise ValueError(f"{cfg.name}: dense attention layers expected")
-        f, blk = layer.ffn.d_ff, ("period", f"block{b}")
-        out[blk + ("norm1",)] = ((n, d), "ones")
-        out[blk + ("norm2",)] = ((n, d), "ones")
-        for name, shape in (("wq", (d, hq)), ("wk", (d, hkv)),
-                            ("wv", (d, hkv)), ("wo", (hq, d))):
-            out[blk + ("mixer", name)] = ((n,) + shape, "fan_in")
-        if layer.ffn.act == "swiglu":
-            ins = ("wi0", "wi1")
-        elif layer.ffn.act == "gelu":
-            ins = ("wi",)
-        else:
-            raise ValueError(f"{cfg.name}: feed-forward {layer.ffn.act!r}")
-        for name in ins:
-            out[blk + ("ffn", name)] = ((n, d, f), "fan_in")
-        out[blk + ("ffn", "wo")] = ((n, f, d), "fan_in")
-    return out
+from bench.harness import arch
 
 
 def _scale(shape, init) -> float:
@@ -65,7 +34,7 @@ def root_key(seed: int, stream: int):
 
 def make(cfg, seed: int, stream: int, dtype) -> dict:
     """The tier's params, made in ``dtype`` by one jitted program."""
-    spec = layout(cfg)
+    spec = arch.of(cfg).layout(cfg)
 
     def build(key):
         flat = {}

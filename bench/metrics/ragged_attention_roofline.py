@@ -1,8 +1,10 @@
 """Kernels: the least device time of the window's ``ragged_attention``
 calls over the time they took, in percent.  Per launch the larger of
-K and V of ``kv_read`` positions plus q and o of ``tokens`` over HBM
-bandwidth, and ``4 * heads * head_dim * kv_pairs`` FLOPs over the bf16
-peak, in every attention layer (``bench/harness/roofline.py``)."""
+the bytes the kernel moves (the cache of ``kv_read`` positions, the
+queries and outputs of ``tokens``) over HBM bandwidth, and the FLOPs of
+``kv_pairs`` query-key pairs over the bf16 peak, in every attention
+layer, as the tier's architecture counts them
+(``bench/harness/roofline.py``, ``bench/harness/arch/``)."""
 from bench.harness import roofline
 
 

@@ -29,11 +29,14 @@ CELL = {"judged_on": "tail", "rate_per_s": 12.0, "slots": 4,
 
 def make_root(tmp: Path, cell: dict = CELL) -> Path:
     """A checkout-shaped directory: ``BENCHMARK.json`` naming one smoke
-    cell, the real metric readers, and the smoke data files."""
+    cell, the real metric readers and architecture files, and the smoke
+    data files."""
     root = tmp / "root"
     for d in ("configs", "traffic", "cells"):
         (root / "bench" / d).mkdir(parents=True)
     shutil.copytree(BENCH / "metrics", root / "bench" / "metrics")
+    shutil.copytree(BENCH / "harness" / "arch", root / "bench" / "harness"
+                    / "arch", ignore=shutil.ignore_patterns("__pycache__"))
     (root / "bench/configs/smoke-cascade.json").write_text(json.dumps(CONFIG))
     (root / "bench/traffic/smoke.json").write_text(json.dumps(TRAFFIC))
     (root / "bench/cells/smoke.chat.json").write_text(json.dumps(cell))

@@ -3,7 +3,7 @@ from __future__ import annotations
 
 import pytest
 
-from bench.harness import peaks
+from bench.harness import arch, peaks
 from repro.configs.base import get_config
 
 
@@ -25,7 +25,8 @@ def test_weights_per_token_match_the_parameter_count(name):
     d, v = cfg.d_model, cfg.vocab_size
     norms = cfg.num_layers * 2 * d + d
     lookup = 0 if cfg.tie_embeddings else v * d
-    weights = peaks.matmul_weights_per_layer(cfg) * cfg.num_layers + d * v
+    weights = arch.of(cfg).matmul_weights_per_layer(cfg) * cfg.num_layers \
+        + d * v
     assert weights == cfg.param_count() - norms - lookup
     assert peaks.token_flops(cfg, 1, True) == 2 * weights \
         + 4 * cfg.num_heads * cfg.head_dim * cfg.num_layers

@@ -12,7 +12,7 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench.harness import cells, peaks, roofline, tracered
+from bench.harness import arch, cells, peaks, roofline, tracered
 from repro.configs.base import Attn, Dense, Layer, ModelConfig
 
 FIXTURES = Path(__file__).parent / "fixtures"
@@ -67,7 +67,8 @@ def test_a_launch_is_bound_by_the_larger_of_bytes_and_flops(bound, peak,
 @pytest.mark.parametrize("tied", [True, False])
 def test_weights_are_counted_once(tied):
     """A tied head once; an untied head whole, its embedding by rows."""
-    assert roofline.weight_bytes(_cfg(tied=tied), 2) == 1000
+    cfg = _cfg(tied=tied)
+    assert arch.of(cfg).weight_bytes(cfg, 2) == 1000
 
 
 @pytest.mark.parametrize("metric,value", [
